@@ -41,6 +41,10 @@ def main() -> None:
 
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     failures = 0
     for name, module in SUITES:
         if only and not any(o in name for o in only):

@@ -57,6 +57,7 @@ from repro.ingest import (
     TraceSource,
     TransportSource,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.batcher_bridge import (
     build_live_cluster,
     build_live_scheduler,
@@ -81,6 +82,7 @@ ap.add_argument("--trace", metavar="PATH", default=None,
                      "trace_event JSON (load via chrome://tracing or "
                      "https://ui.perfetto.dev)")
 args = ap.parse_args()
+enable_compile_cache()
 
 # One tracer spans whatever topology the flags select — wire receive,
 # gateway shed verdicts, window closes, EDF dispatch, completions.

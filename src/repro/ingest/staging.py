@@ -64,7 +64,8 @@ class StagingRing:
 
     ``shape``/``dtype`` are the staged array's device shape — one ring
     per compiled program input (the engine keys rings by
-    ``(kind, mid, seq, batch)``).
+    ``(kind, mid, seq, batch)``). ``device``: where staged arrays are
+    committed (the consuming engine's device; None = JAX's default).
     """
 
     def __init__(
@@ -72,6 +73,7 @@ class StagingRing:
         shape: Sequence[int],
         dtype=np.int32,
         depth: int = 2,
+        device: Optional[jax.Device] = None,
     ):
         if depth < 2:
             raise ValueError(
@@ -80,6 +82,7 @@ class StagingRing:
         self.shape: Tuple[int, ...] = tuple(int(d) for d in shape)
         self.dtype = np.dtype(dtype)
         self.depth = depth
+        self.device = device
         self._scratch = [np.zeros(self.shape, self.dtype) for _ in range(depth)]
         self._next = 0
         self._last_slot: Optional[int] = None
@@ -137,7 +140,7 @@ class StagingRing:
         self.fills += 1
         self.bytes_staged += buf.nbytes
         self._last_slot = slot
-        return jax.device_put(buf)
+        return jax.device_put(buf, self.device)
 
     def attach_consumer(self, wait_fn: Callable[[], object]) -> None:
         """Register the consumer of the MOST RECENTLY staged buffer.

@@ -966,6 +966,10 @@ class TransportServer:
                 ts, reason=f"late: aged {now - msg.sent_at:.4f}s on the wire",
                 seq=msg.seq,
             )
+            if state == "active":
+                # Resolved here: the in-order tail behind it must not
+                # wait out a reorder timeout, nor count this seq lost.
+                self._drain(ts)
             return
         if state == "failover":
             # Slice died, tail not re-admitted yet (parked): hold the
@@ -1012,14 +1016,23 @@ class TransportServer:
                 return
             lo = min(ts.buffer)
             for seq in range(ts.next_seq, lo):
-                self._account_lost(ts, seq)
+                if seq not in ts.seen:  # late-rejected seqs are resolved
+                    self._account_lost(ts, seq)
             ts.next_seq = lo
             self._drain(ts)
 
     def _drain(self, ts: TransportSession) -> None:
-        while ts.next_seq in ts.buffer:
-            payload, _at = self._buffer_pop(ts, ts.next_seq)
-            self._deliver(ts, ts.next_seq, payload)
+        """Deliver the in-order run at ``next_seq``, stepping over seqs
+        already resolved out of order (late-rejected at the door)."""
+        while True:
+            seq = ts.next_seq
+            if seq in ts.buffer:
+                payload, _at = self._buffer_pop(ts, seq)
+                self._deliver(ts, seq, payload)
+            elif seq in ts.seen:
+                ts.next_seq += 1
+            else:
+                return
 
     # -- resolution paths --------------------------------------------------
     def _deliver(self, ts: TransportSession, seq: int, payload) -> None:
@@ -1316,7 +1329,7 @@ class TransportServer:
                 if seq in ts.buffer:
                     payload, _at = self._buffer_pop(ts, seq)
                     self._deliver(ts, seq, payload)
-                else:
+                elif seq not in ts.seen:
                     self._account_lost(ts, seq)
         # Remnants past the FIN total (an adversarial FIN can understate
         # it) are evicted, not vanished — wire_conserved() must hold.

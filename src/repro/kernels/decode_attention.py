@@ -1,11 +1,23 @@
 """Decode (single-token) attention Pallas TPU kernel — flash-decoding.
 
 One new token per sequence attends to its full (or ring) KV cache.
-Schedule: grid (batch, kv_heads, kv_blocks); the G = H/KV query heads of
-one kv head are processed together as a (G, D) tile (G is small for GQA,
-so this keeps the MXU busy with a (G, D) x (D, bk) matmul instead of G
-vector-matrix products). Online-softmax state (m, l, acc) lives in VMEM
-scratch across kv blocks; output written on the last block.
+Schedule: grid (batch, kv_blocks); one grid step holds a kv block of ALL
+kv heads and scores all H query heads against it at once (layout below).
+Online-softmax state (m, l, acc) lives in VMEM scratch across kv blocks;
+output written on the last block.
+
+Layout (what the TPU compiler accepts): the last two dims of every block
+must be multiples of (8, 128) or equal the array's. The (B, S, KV, D)
+cache is therefore viewed as (B, S, KV * D) — lanes ``[h*D, (h+1)*D)``
+of row ``s`` are slot ``s`` of kv head ``h`` — and blocked (1, bk,
+KV * D). Queries go in block-diagonal: (H, KV * D) with query head
+``(h, j)`` in kv head ``h``'s lanes and zeros elsewhere, so ONE
+lane-dense matmul per block scores every head against its own kv head,
+and the PV product's off-diagonal lanes are dropped by the wrapper.
+Per-row ``cursor``/``active`` are scalar-prefetched into SMEM; per-slot
+positions and validity are blocked as (1, 1, bk) tiles of (B, 1, S).
+The (B, S, KV, D) -> (B, S, KV * D) view is a relayout copy of the
+cache on TPU (different tiling), paid per call.
 
 Masking is fully position-driven: the caller passes per-slot absolute
 positions and a validity bitmap, so full caches, ring (sliding-window)
@@ -14,14 +26,10 @@ the same kernel. Fully-masked kv blocks are SKIPPED (``pl.when``), which
 is bit-identical for any row with at least one live slot. On top of that
 sits the slot-arena path: ``active`` is a per-row bitmap (the engine's
 live-slot set — batch size as DATA, not shape), folded into every
-block's mask, so a dead arena row skips ALL its kv blocks — the whole
-row costs two scalar compares per block instead of attention. A row with
-zero live slots outputs exact 0 (the mathematically sensible "attended
-to nothing"), not the uniform mean-of-V an unskipped softmax would give.
-
-The serving engine's decode hot loop is THE perf-critical path of the
-DeepRT reproduction (batched decode job instances are what the GPU/TPU
-executes most of the time), which is why this kernel exists.
+block's skip test, so a dead arena row skips ALL its kv blocks. A row
+with zero live slots outputs exact 0 (the mathematically sensible
+"attended to nothing"), not the uniform mean-of-V an unskipped softmax
+would give.
 """
 from __future__ import annotations
 
@@ -38,23 +46,24 @@ NEG_INF = -1e30
 
 
 def _kernel(
-    q_ref,  # (1, 1, G, D)
-    k_ref,  # (1, bk, 1, D)
+    cursor_ref,  # SMEM (B,) int32 — scalar prefetch
+    active_ref,  # SMEM (B,) int32 (0/1) — live arena slot?
+    q_ref,  # (1, H, KV * D) block-diagonal queries
+    k_ref,  # (1, bk, KV * D)
     v_ref,
-    cursor_ref,  # (1, 1) int32
-    active_ref,  # (1, 1) int32 (0/1) — live arena slot?
-    pos_ref,  # (1, bk) int32
-    valid_ref,  # (1, bk) int32 (0/1)
-    o_ref,  # (1, 1, G, D)
-    m_ref,
-    l_ref,
-    acc_ref,
+    pos_ref,  # (1, 1, bk) int32
+    valid_ref,  # (1, 1, bk) int32 (0/1)
+    o_ref,  # (1, H, KV * D)
+    m_ref,  # (H, 1) f32
+    l_ref,  # (H, 1) f32
+    acc_ref,  # (H, KV * D) f32
     *,
     scale: float,
     window: Optional[int],
     n_kv_blocks: int,
 ):
-    ki = pl.program_id(2)
+    bi = pl.program_id(0)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -62,35 +71,33 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :, :]  # (G, D)
-    cursor = cursor_ref[0, 0]
-    active = active_ref[0, 0] != 0
-    pos = pos_ref[0, :]  # (bk,)
-    valid = valid_ref[0, :] != 0
-
-    mask = jnp.logical_and(jnp.logical_and(pos <= cursor, valid), active)
+    cursor = cursor_ref[bi]
+    pos = pos_ref[0]  # (1, bk)
+    mask = jnp.logical_and(pos <= cursor, valid_ref[0] != 0)
     if window is not None:
         mask = jnp.logical_and(mask, pos > cursor - window)
+    live = jnp.max(mask.astype(jnp.int32)) > 0
 
     # Skip fully-masked kv blocks: a masked block's contribution is
-    # exactly zero (p underflows to 0, alpha = 1), so eliding the two
-    # MXU matmuls is bit-identical. This is what makes dead arena rows
-    # free — ``active=0`` zeroes every block's mask so the row skips ALL
-    # kv blocks — and a ring cache skips its unwritten tail.
-    @pl.when(jnp.any(mask))
+    # exactly zero (p underflows to 0, alpha = 1), so eliding the MXU
+    # matmuls is bit-identical. This is what makes dead arena rows free —
+    # ``active=0`` skips every kv block of the row — and a ring cache
+    # skips its unwritten tail.
+    @pl.when(jnp.logical_and(active_ref[bi] != 0, live))
     def _accumulate():
-        k = k_ref[0, :, 0, :]  # (bk, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0]  # (H, KV * D)
+        k = k_ref[0]  # (bk, KV * D)
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (G, bk)
-        s = jnp.where(mask[None, :], s, NEG_INF)
+        ) * scale  # (H, bk)
+        s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -99,7 +106,7 @@ def _kernel(
     @pl.when(ki == n_kv_blocks - 1)
     def _write():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -130,45 +137,51 @@ def decode_attention(
     kp = jnp.pad(cache_k, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
     vp = jnp.pad(cache_v, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
     pp = jnp.pad(kv_pos, ((0, 0), (0, s_pad - s)), constant_values=2**30)
-    vv = jnp.pad(
-        kv_valid.astype(jnp.int32), ((0, 0), (0, s_pad - s))
-    )
-    # Layout: (B, KV, G, D) so one block = one kv-head's query group.
-    q_kv = q.reshape(b, kv, g, d)
+    vv = jnp.pad(kv_valid.astype(jnp.int32), ((0, 0), (0, s_pad - s)))
+    # Block-diagonal queries: row h*G + j carries query head (h, j) in
+    # lanes [h*D, (h+1)*D) and zeros elsewhere, so one lane-dense
+    # (H, KV*D) x (KV*D, bk) matmul gives every head's logits against
+    # its own kv head (the zero lanes add exact zeros).
+    eye = jnp.eye(kv, dtype=q.dtype)
+    q_bd = (
+        q.reshape(b, kv, g, 1, d) * eye[None, :, None, :, None]
+    ).reshape(b, h, kv * d)
 
     kernel = functools.partial(
-        _kernel,
-        scale=scale,
-        window=window,
-        n_kv_blocks=nk,
+        _kernel, scale=scale, window=window, n_kv_blocks=nk
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, nk),
+        in_specs=[
+            pl.BlockSpec((1, h, kv * d), lambda b_, k_, *_: (b_, 0, 0)),
+            pl.BlockSpec((1, block_k, kv * d), lambda b_, k_, *_: (b_, k_, 0)),
+            pl.BlockSpec((1, block_k, kv * d), lambda b_, k_, *_: (b_, k_, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, k_, *_: (b_, 0, k_)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, k_, *_: (b_, 0, k_)),
+        ],
+        out_specs=pl.BlockSpec((1, h, kv * d), lambda b_, k_, *_: (b_, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, kv * d), jnp.float32),
+        ],
     )
     out = pl.pallas_call(
         kernel,
-        grid=(b, kv, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, h_, k_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, k_: (b_, k_, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, k_: (b_, k_, h_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, k_: (b_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, k_: (b_, 0)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, k_: (b_, k_)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, k_: (b_, k_)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h_, k_: (b_, h_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, kv * d), q.dtype),
         interpret=interpret,
     )(
-        q_kv,
-        kp,
-        vp,
-        cursor[:, None].astype(jnp.int32),
-        active[:, None].astype(jnp.int32),
-        pp.astype(jnp.int32),
-        vv,
+        cursor.astype(jnp.int32),
+        active.astype(jnp.int32),
+        q_bd,
+        kp.reshape(b, s_pad, kv * d),
+        vp.reshape(b, s_pad, kv * d),
+        pp.astype(jnp.int32)[:, None, :],
+        vv[:, None, :],
     )
+    # Keep each query head's own kv-head lanes (the diagonal block); the
+    # off-diagonal lanes mixed other heads' values and are discarded.
+    out = (out.reshape(b, kv, g, kv, d) * eye[None, :, None, :, None]).sum(3)
     return out.reshape(b, 1, h, d)

@@ -5,6 +5,12 @@ innermost and TPU grids execute sequentially, so the online-softmax
 running state (m, l, acc) lives in VMEM scratch and carries across kv
 iterations; the output tile is written once on the last kv block.
 
+Layout: the kernel reads head-major (B, H, S, D) views, so every block's
+last two dims are (rows, D) — a multiple of 8 by the full head dim, the
+shape the TPU compiler accepts. The wrapper transposes q/k/v in and the
+output back; (B, S, H, D) blocks of one head would end in (1, D), which
+the compiler refuses.
+
 VMEM working set per grid step (f32):
     q tile (bq, D) + k/v tiles (bk, D) + logits (bq, bk) + acc (bq, D)
 With bq = bk = 128, D <= 256 that is well under 1 MiB — far inside the
@@ -69,9 +75,9 @@ def _kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :]  # (bq, D)
-        k = k_ref[0, :, 0, :]  # (bk, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]  # (bq, D)
+        k = k_ref[0, 0]  # (bk, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q,
             k,
@@ -86,12 +92,12 @@ def _kernel(
         if window is not None:
             mask = jnp.logical_and(mask, k_pos > q_pos - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype),
             v,
             (((1,), (0,)), ((), ())),
@@ -102,7 +108,7 @@ def _kernel(
     @pl.when(ki == n_kv_blocks - 1)
     def _write():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -131,9 +137,10 @@ def flash_attention(
     nk = math.ceil(s / block_k)
     s_pad_q = nq * block_q
     s_pad_k = nk * block_k
-    qp = jnp.pad(q, ((0, 0), (0, s_pad_q - s), (0, 0), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, s_pad_k - s), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, s_pad_k - s), (0, 0), (0, 0)))
+    # Head-major views: (B, H, S_pad, D) / (B, KV, S_pad, D).
+    qp = jnp.pad(q, ((0, 0), (0, s_pad_q - s), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kp = jnp.pad(k, ((0, 0), (0, s_pad_k - s), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vp = jnp.pad(v, ((0, 0), (0, s_pad_k - s), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _kernel,
@@ -150,26 +157,26 @@ def flash_attention(
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec(
-                (1, block_q, 1, d), lambda b_, h_, q_, k_: (b_, q_, h_, 0)
+                (1, 1, block_q, d), lambda b_, h_, q_, k_: (b_, h_, q_, 0)
             ),
             pl.BlockSpec(
-                (1, block_k, 1, d),
-                lambda b_, h_, q_, k_: (b_, k_, h_ // group, 0),
+                (1, 1, block_k, d),
+                lambda b_, h_, q_, k_: (b_, h_ // group, k_, 0),
             ),
             pl.BlockSpec(
-                (1, block_k, 1, d),
-                lambda b_, h_, q_, k_: (b_, k_, h_ // group, 0),
+                (1, 1, block_k, d),
+                lambda b_, h_, q_, k_: (b_, h_ // group, k_, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_q, 1, d), lambda b_, h_, q_, k_: (b_, q_, h_, 0)
+            (1, 1, block_q, d), lambda b_, h_, q_, k_: (b_, h_, q_, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s_pad_q, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_pad_q, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :s]
+    return out.transpose(0, 2, 1, 3)[:, :s]

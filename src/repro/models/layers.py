@@ -14,6 +14,7 @@ All model code is purely functional: ``f(params, inputs) -> outputs``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -36,6 +37,14 @@ class Param:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "std"))
+def _scaled_normal(key, shape, dtype, std) -> jax.Array:
+    # One fused program per leaf: only the ``dtype`` result is ever
+    # materialized (eagerly, a (40, 2048, 8192) leaf would hold two f32
+    # copies on the device before the cast).
+    return (std * jax.random.normal(key, shape)).astype(dtype)
+
+
 def _init_leaf(key, p: Param, dtype) -> jax.Array:
     if p.init == "zeros":
         return jnp.zeros(p.shape, dtype)
@@ -43,13 +52,13 @@ def _init_leaf(key, p: Param, dtype) -> jax.Array:
         return jnp.ones(p.shape, dtype)
     if p.init == "embed":
         std = p.scale if p.scale is not None else 1.0
-        return (std * jax.random.normal(key, p.shape)).astype(dtype)
+        return _scaled_normal(key, p.shape, dtype, std)
     # fan-in scaled normal
     fan_in = p.shape[0] if len(p.shape) > 1 else max(p.shape[0], 1)
     if len(p.shape) == 3:  # stacked experts / stacked layers: fan-in is dim 1
         fan_in = p.shape[1]
     std = p.scale if p.scale is not None else 1.0 / math.sqrt(fan_in)
-    return (std * jax.random.normal(key, p.shape)).astype(dtype)
+    return _scaled_normal(key, p.shape, dtype, std)
 
 
 def build_params(spec: Any, key: jax.Array, dtype=jnp.float32) -> Any:

@@ -37,6 +37,7 @@ from __future__ import annotations
 from functools import partial
 from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -423,7 +424,8 @@ def build_live_cluster(
 
     One shared WallClock; per slice, its OWN InferenceEngine (resident
     KV arena sized by ``bucketing.slice_arena_slots`` under that slice's
-    Phase-1 utilization bound), its own AsyncDevice, and its own
+    Phase-1 utilization bound) on its own chip — slice i runs on
+    ``jax.devices()[i % n]`` — its own AsyncDevice, and its own
     profiled WCET table — the arena is device-resident state, so slicing
     the fleet slices the arenas (ROADMAP open item, shipped here).
     Placement, spill-on-reject, per-request arena-row leases, and
@@ -472,11 +474,12 @@ def build_live_cluster(
     cluster = ClusterScheduler(loop=loop, watchdog=watchdog)
     slices: Dict[str, LiveSlice] = {}
     max_batch = max(*batch_sizes, nonrt_cap)
-    for name in slice_names:
+    devices = jax.devices()
+    for i, name in enumerate(slice_names):
         bound = bounds.get(name, 1.0)
         engine = InferenceEngine(
             configs, max_slots=slice_arena_slots(max_batch, bound),
-            chunk_depth=chunk_depth,
+            chunk_depth=chunk_depth, device=devices[i % len(devices)],
         )
         table = profile_engine(
             engine, cats, batch_sizes, runs=profile_runs,

@@ -81,6 +81,31 @@ from repro.models import model_for
 from repro.models.kvcache import cache_nbytes, cache_reset_rows
 
 
+def prefill_program(model):
+    """The jitted prefill step: (params, tokens (B, S)) -> next tokens."""
+
+    def run(params, tokens):
+        logits, _ = model.forward(params, tokens)
+        return logits[:, -1].argmax(-1)
+
+    return jax.jit(run)
+
+
+def decode_program(model, seq: int, donate: bool):
+    """The jitted slot-arena decode step for arenas of length ``seq``:
+    (params, cache, tok, cur, active) -> (logits, new cache, new cur).
+    ``donate`` donates the cache, so the arena updates in place."""
+
+    def run(params, cache, tok, cur, active):
+        logits, new_cache = model.decode_step(
+            params, cache, tok, cur, active=active
+        )
+        new_cur = jnp.where(active, jnp.minimum(cur + 1, seq - 1), cur)
+        return logits, new_cache, new_cur
+
+    return jax.jit(run, donate_argnums=(1,) if donate else ())
+
+
 @dataclass
 class StepHandle:
     """One in-flight dispatched step (outputs may still be computing)."""
@@ -138,6 +163,7 @@ class InferenceEngine:
         max_slots: int = 8,
         staging_depth: int = 2,
         chunk_depth: int = 1,
+        device: Optional[jax.Device] = None,
     ):
         """``donate_cache``: None resolves by backend (module docstring);
         explicit True/False force it — the benchmark A/Bs both arms.
@@ -154,6 +180,12 @@ class InferenceEngine:
         sized ``max(staging_depth, chunk_depth + 1)`` — the depth must
         be fixed before a ring's first use, hence a construction-time
         parameter. 1 = chunking off (rings stay at ``staging_depth``).
+        ``device``: the one device this engine runs on (default
+        ``jax.devices()[0]``). Params, arenas, cursors/bitmaps and staged
+        inputs are COMMITTED there (``jax.device_put(x, device)``), so
+        every compiled step runs on it whichever thread dispatches —
+        ``jax.default_device`` alone would not do: ``jit`` places
+        uncommitted inputs on the calling thread's default device.
         """
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
@@ -161,15 +193,21 @@ class InferenceEngine:
             raise ValueError(f"chunk_depth must be >= 1, got {chunk_depth}")
         self.configs = dict(configs)
         self.models = {mid: model_for(cfg) for mid, cfg in configs.items()}
+        self.device = device if device is not None else jax.devices()[0]
         if donate_cache is None:
-            donate_cache = jax.default_backend() != "cpu"
+            donate_cache = self.device.platform != "cpu"
         self.donate_cache = donate_cache
         self.masked_decode = masked_decode
         self.max_slots = max_slots
-        key = jax.random.PRNGKey(seed)
         self.params = {}
-        for i, (mid, model) in enumerate(self.models.items()):
-            self.params[mid] = model.init(jax.random.fold_in(key, i))
+        # Created on the engine's device (no staging through device 0),
+        # then committed there.
+        with jax.default_device(self.device):
+            key = jax.random.PRNGKey(seed)
+            for i, (mid, model) in enumerate(self.models.items()):
+                self.params[mid] = self._put(
+                    model.init(jax.random.fold_in(key, i))
+                )
         self._compiled: Dict[Tuple, Any] = {}
         self._arenas: Dict[Tuple[str, int], SlotArena] = {}
         self.staging_depth = staging_depth
@@ -192,6 +230,16 @@ class InferenceEngine:
         # Measured padding/compile accounting.
         self.stats: Dict[str, int] = {}
         self.reset_stats()
+
+    def _put(self, x):
+        """Commit ``x`` (array or pytree) to this engine's device."""
+        return jax.device_put(x, self.device)
+
+    def _row_mask(self, rows: Sequence[int]) -> jax.Array:
+        """(max_slots,) bool mask of ``rows``, committed to the device."""
+        mask = np.zeros((self.max_slots,), bool)
+        mask[list(rows)] = True
+        return self._put(mask)
 
     def reset_stats(self) -> None:
         """Zero the padding/dispatch/compile counters. build_live_scheduler
@@ -228,13 +276,7 @@ class InferenceEngine:
         key = ("prefill", mid, seq, batch)
         if key not in self._compiled:
             self.stats["prefill_compiles"] += 1
-            model = self.models[mid]
-
-            def run(params, tokens):
-                logits, _ = model.forward(params, tokens)
-                return logits[:, -1].argmax(-1)
-
-            self._compiled[key] = jax.jit(run)
+            self._compiled[key] = prefill_program(self.models[mid])
         return self._compiled[key]
 
     def _decode_fn(self, mid: str, seq: int):
@@ -246,19 +288,9 @@ class InferenceEngine:
         key = ("decode", mid, seq)
         if key not in self._compiled:
             self.stats["decode_compiles"] += 1
-            model = self.models[mid]
-
-            def run(params, cache, tok, cur, active):
-                logits, new_cache = model.decode_step(
-                    params, cache, tok, cur, active=active
-                )
-                new_cur = jnp.where(
-                    active, jnp.minimum(cur + 1, seq - 1), cur
-                )
-                return logits, new_cache, new_cur
-
-            donate = (1,) if self.donate_cache else ()
-            self._compiled[key] = jax.jit(run, donate_argnums=donate)
+            self._compiled[key] = decode_program(
+                self.models[mid], seq, self.donate_cache
+            )
         return self._compiled[key]
 
     def _decode_chunk_fn(self, mid: str, seq: int, k: int):
@@ -309,11 +341,13 @@ class InferenceEngine:
         """The resident decode arena for (mid, seq), created on first use."""
         key = (mid, seq)
         if key not in self._arenas:
+            with jax.default_device(self.device):
+                cache = self.models[mid].init_cache(self.max_slots, seq)
             self._arenas[key] = SlotArena(
-                cache=self.models[mid].init_cache(self.max_slots, seq),
+                cache=self._put(cache),
                 max_slots=self.max_slots,
-                cur=jnp.zeros((self.max_slots,), jnp.int32),
-                active=jnp.zeros((self.max_slots,), bool),
+                cur=self._put(np.zeros((self.max_slots,), np.int32)),
+                active=self._put(np.zeros((self.max_slots,), bool)),
                 free=list(range(self.max_slots)),
             )
         return self._arenas[key]
@@ -346,7 +380,7 @@ class InferenceEngine:
             )
         slots = tuple(sorted(arena.free)[:n])
         arena.free = [s for s in arena.free if s not in slots]
-        rows = jnp.zeros((arena.max_slots,), bool).at[jnp.array(slots)].set(True)
+        rows = self._row_mask(slots)
         arena.cache = self._reset_fn(arena.cache, rows)
         arena.cur = jnp.where(rows, jnp.int32(start_pos), arena.cur)
         arena.active = arena.active | rows
@@ -370,7 +404,7 @@ class InferenceEngine:
         if not_live:
             raise ValueError(f"double free / never-allocated slots {not_live}")
         arena.free.extend(ids)
-        rows = jnp.zeros((arena.max_slots,), bool).at[jnp.array(ids)].set(True)
+        rows = self._row_mask(ids)
         arena.active = arena.active & ~rows
 
     def arena_nbytes(self, mid: str, seq: int) -> int:
@@ -396,7 +430,7 @@ class InferenceEngine:
             depth = self.staging_depth
             if kind == "decode":
                 depth = max(depth, self.max_chunk_depth + 1)
-            ring = StagingRing(shape, np.int32, depth=depth)
+            ring = StagingRing(shape, np.int32, depth=depth, device=self.device)
             self._rings[key] = ring
         return ring
 
@@ -499,15 +533,9 @@ class InferenceEngine:
             k = self.max_slots  # blind padding: every row does full work
         key = (mid, seq, k)
         if key not in self._decode_inputs:
-            m = self.max_slots
-            cur = jnp.concatenate(
-                [
-                    jnp.full((k,), seq - 1, jnp.int32),
-                    jnp.zeros((m - k,), jnp.int32),
-                ]
-            )
-            active = (jnp.arange(m) < k)
-            self._decode_inputs[key] = (cur, active)
+            live = np.arange(self.max_slots) < k
+            cur = np.where(live, seq - 1, 0).astype(np.int32)
+            self._decode_inputs[key] = (self._put(cur), self._put(live))
         return self._decode_inputs[key]
 
     # ----- execution ---------------------------------------------------------
@@ -616,11 +644,7 @@ class InferenceEngine:
                     raise ValueError(
                         f"step_rows {extra} are not live rows {sorted(ids)}"
                     )
-                rows = (
-                    jnp.zeros((m,), bool).at[jnp.array(step)].set(True)
-                    if step else jnp.zeros((m,), bool)
-                )
-                active = arena.active & rows
+                active = arena.active & self._row_mask(step)
         k = batch_size if self.masked_decode else m
         self.stats["real_rows"] += batch_size
         self.stats["bucket_rows"] += m
@@ -780,7 +804,7 @@ class InferenceEngine:
         m = self.max_slots
         if step_rows is None or all(r is None for r in step_rows):
             if k not in self._full_masks:
-                self._full_masks[k] = jnp.ones((k, m), bool)
+                self._full_masks[k] = self._put(np.ones((k, m), bool))
             return self._full_masks[k]
         buf = np.zeros((k, m), bool)
         for i, rows_i in enumerate(step_rows):
@@ -789,7 +813,7 @@ class InferenceEngine:
             else:
                 for s in rows_i:
                     buf[i, int(s)] = True
-        return jnp.asarray(buf)
+        return self._put(buf)
 
     def execute(
         self, mid: str, shape_key: Tuple[int, ...], batch_size: int,

@@ -41,12 +41,22 @@ from repro.ingest import (
     TraceSource,
 )
 from repro.core.traces import TraceSpec
+from repro.launch.checks import LOGIT_RTOL, compare_logits
 from repro.models import model_for
 from repro.serving.engine import InferenceEngine
 
 MID = "granite-3-2b"
 SEQ = 16
 SEQ_D = 8
+
+
+def _logits_match(got, ref) -> bool:
+    """An arena row (batch max_slots) vs a batch-1 reference runs the
+    same f32 math in another reduction order (XLA picks it by batch
+    shape): ulp-level gaps (2e-7 on logits of ~0.4 observed). Compare
+    under the tolerance and argmax rule of ``repro.launch.checks``.
+    The other comparisons in this file stay exact."""
+    return compare_logits(got, ref, LOGIT_RTOL["float32"])["ok"]
 
 
 # ---------------------------------------------------------------------------
@@ -323,9 +333,10 @@ class TestPayloadFidelity:
     def test_idle_leased_rows_do_not_consume_phantom_tokens(self):
         """A leased stream with no frame in a window stays INACTIVE for
         that step (step_rows): its cursor is frozen and its KV history
-        never contains a phantom zero token — every stream's row stays
-        bit-identical to a dense reference replaying only ITS OWN
-        ingested tokens, at every step, not just the first."""
+        never contains a phantom zero token — every stream's row matches
+        a dense reference replaying only ITS OWN ingested tokens (under
+        the batch-shape tolerance above), at every step, not just the
+        first."""
         e = _engine(max_slots=4)
         model = model_for(tiny(MID))
         step = jax.jit(model.decode_step)
@@ -352,14 +363,14 @@ class TestPayloadFidelity:
             e.params[MID], cache, jnp.array([5], jnp.int32),
             jnp.ones((1,), jnp.int32),
         )
-        assert bool(jnp.all(out[0] == ref_a[0]))
+        assert _logits_match(out[0], ref_a[0])
         # B == dense ref of its FIRST token at cursor 0: the idle
         # window left no trace.
         ref_b, _ = step(
             e.params[MID], model.init_cache(1, SEQ_D),
             jnp.array([7], jnp.int32), jnp.zeros((1,), jnp.int32),
         )
-        assert bool(jnp.all(out[1] == ref_b[0]))
+        assert _logits_match(out[1], ref_b[0])
 
     def test_step_rows_must_be_live(self):
         e = _engine(max_slots=4)
@@ -676,9 +687,10 @@ class TestGatewayLiveCluster:
 
     def test_slot_payloads_route_to_leased_rows(self, served):
         """The FIRST decode job on each slice: every index-0 frame's
-        ingested token must produce, at some arena row, logits
-        bit-identical to a fresh single-row reference fed that token at
-        cursor 0 — payloads reached their streams' resident rows.
+        ingested token must produce, at some arena row, logits matching
+        (under the batch-shape tolerance above) a fresh single-row
+        reference fed that token at cursor 0 — payloads reached their
+        streams' resident rows.
         (Later jobs depend on each row's KV history: continuous
         batching steps ALL leased rows every window, so only the first
         job has a clean-slate reference.)"""
@@ -709,7 +721,7 @@ class TestGatewayLiveCluster:
                 )
                 matches = [
                     r for r in range(out.shape[0])
-                    if np.array_equal(out[r], np.asarray(ref)[0])
+                    if _logits_match(out[r], ref[0])
                 ]
                 assert matches, (sl.spec.name, frame.request_id, tok)
                 checked += 1

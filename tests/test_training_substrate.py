@@ -148,7 +148,6 @@ class TestCompression:
         the true mean, and error feedback keeps the bias bounded over
         repeated rounds."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         devs = np.array(jax.devices()[:1])
         if len(jax.devices()) < 2:
@@ -162,9 +161,9 @@ class TestCompression:
                 return compressed_pod_mean(g, r, "pod")
 
             out, new_r = jax.jit(
-                shard_map(
+                jax.shard_map(
                     f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-                    check_rep=False,
+                    check_vma=False,
                 )
             )(g, r)
             np.testing.assert_allclose(

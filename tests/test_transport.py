@@ -284,6 +284,30 @@ class TestReassembly:
         assert 2 not in ts.delivered_log
         assert ts.session.frames_dropped >= 1
         assert ts.session.last_shed_reason.startswith("late")
+        # Resolved once, as a drop: its seq is not declared lost too.
+        assert ts.net_lost == 0
+        assert cluster.aggregate_metrics()["ingested_frames"] == 8
+        assert _conserved(cluster) and ts.wire_conserved()
+
+    def test_late_frame_in_order_does_not_stall_the_tail(self):
+        # Frame 2 arrives in order but past its deadline budget (sent at
+        # 6.0, lands at 7.5, deadline 1.0); frame 3 lands on time at 9.0
+        # and is delivered then, not held for a reorder timeout behind
+        # the rejected seq.
+        plan = LinkPlan((LinkFault(LINK_DELAY, 2, delay=1.5),))
+        cluster, _server, ts, _src, _client = self._run(
+            plan, n_frames=6, period=3.0, deadline=1.0
+        )
+        assert ts.late_rejected == 1 and ts.net_lost == 0
+        assert ts.delivered_log == [0, 1, 3, 4, 5]
+        assert cluster.aggregate_metrics()["ingested_frames"] == 6
+        sl = cluster.slices[ts.session.slice_name]
+        arrivals = {
+            idx: arrival
+            for (_rid, idx), (arrival, _dl, _done)
+            in sl.scheduler.metrics.frame_records.items()
+        }
+        assert arrivals[3] == pytest.approx(9.0)
         assert _conserved(cluster) and ts.wire_conserved()
 
     def test_deliveries_are_deadline_stamped_at_arrival(self):
