@@ -530,7 +530,7 @@ class ClusterScheduler:
         """Add a pre-built slice (the live factory's entry point)."""
         self.slices[sl.spec.name] = sl
         if self.tracer is not None:
-            sl.scheduler.attach_tracer(self.tracer, tag=sl.spec.name)
+            self._trace_slice(sl, self.tracer)
         return sl
 
     def attach_tracer(self, tracer) -> None:
@@ -541,7 +541,14 @@ class ClusterScheduler:
         self.tracer = tracer
         self.health.tracer = tracer
         for sl in self.slices.values():
-            sl.scheduler.attach_tracer(tracer, tag=sl.spec.name)
+            self._trace_slice(sl, tracer)
+
+    @staticmethod
+    def _trace_slice(sl: Slice, tracer) -> None:
+        sl.scheduler.attach_tracer(tracer, tag=sl.spec.name)
+        engine = getattr(sl, "engine", None)  # a LiveSlice's engine spans
+        if engine is not None:
+            engine.tracer = tracer
 
     def mark_slow(self, name: str, factor: Optional[float] = None) -> float:
         """Straggler: scale the slice's WCET table for future admissions;
@@ -796,9 +803,16 @@ class ClusterScheduler:
 
     def aggregate_metrics(self) -> Dict[str, float]:
         total = missed = jobs = shed = lost = delivered = retries = 0
+        dispatches, dispatch_s = 0, 0.0
+        now = self.loop.now
+        idle = dict.fromkeys((T.DEVICE_RUNNING,) + T.IDLE_STATES, 0.0)
         e2e = LatencyHistogram()
         for sl in self.slices.values():
+            for state, secs in sl.scheduler.worker.idle_clock.totals(now).items():
+                idle[state] += secs
             m = sl.scheduler.metrics
+            dispatches += m.dispatch_count
+            dispatch_s += m.dispatch_overhead_sum
             total += m.completed_frames
             missed += m.missed_frames
             jobs += m.job_count
@@ -829,6 +843,16 @@ class ClusterScheduler:
             "parked_admitted": len(self.parked_admitted),
             "parked_expired": len(self.parked_expired),
             "parked_cancelled": len(self.parked_cancelled),
+            # Device-idle split, host time per dispatch and loop lag
+            # (running sums; a reader takes differences over a window).
+            "device_busy_s": idle[T.DEVICE_RUNNING],
+            "device_idle_held_s": idle[T.IDLE_HELD],
+            "device_idle_ready_s": idle[T.IDLE_READY],
+            "device_idle_empty_s": idle[T.IDLE_EMPTY],
+            "dispatch_host_s": dispatch_s,
+            "dispatches": dispatches,
+            "loop_late_s": getattr(self.loop, "loop_late_s", 0.0),
+            "loop_callbacks": getattr(self.loop, "loop_callbacks", 0),
         }
 
     def telemetry_snapshot(self) -> Dict:

@@ -214,23 +214,25 @@ class DisBatcher:
         st = self._cats[cat]
         if not st.frames:
             return None
-        frames, st.frames = st.frames, []
-        job = JobInstance(
-            category=cat,
-            frames=frames,
-            release_time=release_time,
-            relative_deadline=st.window,
-            shape_key=st.shape_override or cat.shape_key,
-        )
         tr = self.tracer
-        if tr is not None:
-            label = str(cat)
-            for f in frames:
-                tr.emit(T.WINDOW_CLOSE, release_time, f.request_id, f.index,
-                        where=self.tracer_tag, cat=label,
-                        meta={"job_id": job.job_id, "batch": len(frames),
-                              "window": st.window})
-        self.emit(job)
+        with T.span(tr, "deeprt.disbatcher.flush") as sp:
+            frames, st.frames = st.frames, []
+            job = JobInstance(
+                category=cat,
+                frames=frames,
+                release_time=release_time,
+                relative_deadline=st.window,
+                shape_key=st.shape_override or cat.shape_key,
+            )
+            if tr is not None:
+                sp.set_metadata(job_id=job.job_id)
+                label = str(cat)
+                for f in frames:
+                    tr.emit(T.WINDOW_CLOSE, release_time, f.request_id, f.index,
+                            where=self.tracer_tag, cat=label,
+                            meta={"job_id": job.job_id, "batch": len(frames),
+                                  "window": st.window})
+            self.emit(job)
         return job
 
     def earliest_next_joint(self, realtime_only: bool = False) -> Optional[float]:

@@ -213,6 +213,11 @@ class EDFWorker:
         # every hook below is a single identity check on the hot path.
         self.tracer = None
         self.tracer_tag: Optional[str] = None  # slice name in a cluster
+        # Device-idle split (always on): the time the device runs no job,
+        # as held / ready / empty. ``frames_held_fn`` says whether frames
+        # wait in a DisBatcher window (the scheduler wires it).
+        self.idle_clock = T.IdleClock(loop.now)
+        self.frames_held_fn: Optional[Callable[[], bool]] = None
 
     # ----- queue interface (DisBatcher emit target) ---------------------
     def submit(self, job: JobInstance) -> None:
@@ -232,7 +237,22 @@ class EDFWorker:
                 tr.emit(T.EDF_ENQUEUE, now, f.request_id, f.index,
                         where=self.tracer_tag, cat=str(job.category),
                         meta={"job_id": job.job_id, "deadline": job.deadline})
+        self.note_idle_state()
         self._schedule_dispatch()
+
+    def note_idle_state(self) -> None:
+        """Mark the idle clock with the state the worker sees now, if
+        the device is idle (while it runs, or holds a finished job the
+        loop has not handled yet, the device's own marks stand)."""
+        if not self.device.idle:
+            return
+        if self.queue:
+            state = T.IDLE_READY
+        elif self.frames_held_fn is not None and self.frames_held_fn():
+            state = T.IDLE_HELD
+        else:
+            state = T.IDLE_EMPTY
+        self.idle_clock.mark(state, self.loop.now)
 
     def _schedule_dispatch(self) -> None:
         """Defer the pick-next-job decision to a PRIO_DISPATCH event at the
@@ -265,6 +285,12 @@ class EDFWorker:
                 # flush_early emitted a job via submit() -> already started.
                 return
             return
+        with T.span(self.tracer, "deeprt.edf.dispatch") as sp:
+            self._start_next(sp)
+
+    def _start_next(self, sp) -> None:
+        """Pick the next job and submit it to the idle device; ``sp`` is
+        the ``deeprt.edf.dispatch`` span, given the job's id once picked."""
         t_host = _time.perf_counter()
         job = self._pick_job()
         if job is None:
@@ -282,6 +308,8 @@ class EDFWorker:
             for inner in job.jobs:
                 inner.start_time = job.start_time
                 inner.profiled_wcet = self.profiled_fn(inner)
+        if self.tracer is not None:
+            sp.set_metadata(job_id=job.job_id)
         actual = self.exec_time_fn(job)
         jb = self.job_bytes_fn(job) if self.job_bytes_fn is not None else 0.0
         try:
@@ -309,6 +337,7 @@ class EDFWorker:
                     priority=getattr(self.loop, "PRIO_DISPATCH", 3),
                 )
             return
+        self.idle_clock.mark(T.DEVICE_RUNNING, self.loop.now)
         if self.tracer is not None:
             self._trace_dispatch(job)
         if isinstance(job, ChunkJob) and job.k > 1:
@@ -518,6 +547,7 @@ class EDFWorker:
             elif actual < job.profiled_wcet - 1e-9:
                 if self.on_underrun is not None:
                     self.on_underrun(job, job.profiled_wcet - actual)
+        self.note_idle_state()
         # Device calls on_idle -> on_device_idle -> dispatch, via the
         # scheduler wiring; also schedule directly for standalone use.
         if self.device.on_idle is None:

@@ -112,6 +112,13 @@ class DeepRT:
             ),
         )
         self.disbatcher = DisBatcher(self.loop, emit=self.worker.submit)
+        self.worker.frames_held_fn = self.disbatcher.has_pending_frames
+        # A live device marks the worker's idle clock at the instant its
+        # waiter sees a job finish (AsyncDevice, possibly behind a
+        # FaultyDevice wrapper); a simulated device completes on the loop.
+        for dev in (self.device, getattr(self.device, "inner", None)):
+            if dev is not None and "idle_clock" in getattr(dev, "__dict__", {}):
+                dev.idle_clock = self.worker.idle_clock
         self.admission = AdmissionControl(table)
         self.adaptation = AdaptationModule(
             table, self.disbatcher, shrink_fn=shrink_fn, enabled=adaptation_enabled
@@ -143,8 +150,10 @@ class DeepRT:
         self.worker.tracer_tag = tag
         self.disbatcher.tracer = tracer
         self.disbatcher.tracer_tag = tag
-        # Devices that carry a measured-completion lane (AsyncDevice —
-        # possibly behind a FaultyDevice wrapper) get the tracer too;
+        if "tracer" in getattr(self.loop, "__dict__", {}):
+            self.loop.tracer = tracer  # WallClock: anchor + wait spans
+        # Live devices (AsyncDevice — possibly behind a FaultyDevice
+        # wrapper) get the tracer too, for their profiler spans;
         # SequentialDevice defines no ``tracer`` slot and is skipped.
         for dev in (self.device, getattr(self.device, "inner", None)):
             if dev is not None and "tracer" in getattr(dev, "__dict__", {}):
@@ -204,15 +213,16 @@ class DeepRT:
             self._admit(request, external_arrivals)
             return AdmissionResult(admitted=True, phase=0, utilization=0.0,
                                    reason="non-RT: admission bypassed")
-        state = snapshot_from_scheduler(
-            now=now,
-            disbatcher=self.disbatcher,
-            queued_jobs=self.worker.queue.snapshot(),
-            device_free_at=self.device.busy_until or now,
-            table=self.table,
-            pending=request,
-        )
-        result = self.admission.admit(state, self.utilization_bound)
+        with T.span(self.tracer, "deeprt.admission"):
+            state = snapshot_from_scheduler(
+                now=now,
+                disbatcher=self.disbatcher,
+                queued_jobs=self.worker.queue.snapshot(),
+                device_free_at=self.device.busy_until or now,
+                table=self.table,
+                pending=request,
+            )
+            result = self.admission.admit(state, self.utilization_bound)
         if result.admitted:
             self._admit(request, external_arrivals)
         else:
@@ -300,6 +310,7 @@ class DeepRT:
                 self.disbatcher._flush(request.category, now)
         # Non-idling: an idle device should not sit on waiting frames.
         if self.device.idle and not self.worker.queue:
+            self.worker.note_idle_state()
             self.worker.on_device_idle()
         return frame
 

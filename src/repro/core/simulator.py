@@ -22,7 +22,7 @@ import time as _time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-from repro.core.telemetry import LatencyHistogram
+from repro.core.telemetry import LatencyHistogram, clock_anchor, span
 
 
 class EventLoop:
@@ -108,6 +108,11 @@ class WallClock:
     - ``hold()`` / ``release()``— keep ``run`` alive while external work
       (an in-flight device execution) will post a future completion even
       though the heap is momentarily empty.
+
+    Loop lag (always on): ``loop_late_s`` sums how late each callback
+    ran against its scheduled time, over ``loop_callbacks`` callbacks.
+    With a ``tracer`` attached, each ``run`` emits the ``deeprt.clock``
+    anchor and every sleep is a ``deeprt.loop.wait`` profiler span.
     """
 
     PRIO_ARRIVAL = 0
@@ -122,6 +127,10 @@ class WallClock:
         self._cancelled: set = set()
         self._cond = threading.Condition()
         self._holds = 0
+        self.loop_late_s = 0.0
+        self.loop_callbacks = 0
+        # Frame-lifecycle tracer (core/telemetry.py); None = off.
+        self.tracer = None
 
     @property
     def now(self) -> float:
@@ -157,6 +166,8 @@ class WallClock:
         self._cancelled.add(event_id)
 
     def run(self, until: Optional[float] = None) -> None:
+        if self.tracer is not None:
+            clock_anchor(self.tracer, self.now)
         while True:
             fn = None
             with self._cond:
@@ -171,16 +182,20 @@ class WallClock:
                             if eid in self._cancelled:
                                 self._cancelled.discard(eid)
                                 continue
+                            self.loop_late_s -= wait
+                            self.loop_callbacks += 1
                             fn = _fn
                             break
                         # Sleep until exactly the next event (or a post()).
-                        self._cond.wait(timeout=wait)
+                        with span(self.tracer, "deeprt.loop.wait"):
+                            self._cond.wait(timeout=wait)
                     elif self._holds > 0:
                         # Heap empty but a device execution is in flight;
                         # its completion will be post()ed from the waiter.
                         if until is not None and self.now > until:
                             return
-                        self._cond.wait(timeout=0.05)
+                        with span(self.tracer, "deeprt.loop.wait"):
+                            self._cond.wait(timeout=0.05)
                     else:
                         return
             # Execute outside the lock: callbacks may schedule() freely.

@@ -34,6 +34,14 @@ from wire to completion:
   timeline viewing in ``chrome://tracing`` / Perfetto, and a generic
   ``/metrics``-style text exposition (:func:`render_text`) over the
   cluster's JSON snapshot (``ClusterScheduler.telemetry_snapshot``).
+- Profiler spans (:func:`span`): while a tracer is attached, the serving
+  loop's stages open ``jax.profiler.TraceAnnotation`` spans named
+  ``deeprt.*`` (``SPANS``), so a device trace shows what the host was
+  doing in each idle gap. Off, :func:`span` returns one shared no-op.
+  :func:`clock_anchor` ties the ring's ``loop.now`` to the profiler's
+  clock (``deeprt.clock``), so ring events map onto the device trace.
+- :class:`IdleClock` — always on, O(1) per transition: splits the time
+  a device is not running a job into ``held`` / ``ready`` / ``empty``.
 
 Adding a stage: pick a constant below, ``emit`` it from the component
 with ``loop.now``, and — if it should participate in attribution — stamp
@@ -46,6 +54,7 @@ from __future__ import annotations
 import json
 import math
 from collections import deque
+from contextlib import nullcontext
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 # ---------------------------------------------------------------------------
@@ -63,7 +72,6 @@ EDF_DISPATCH = "edf_dispatch"          # job popped + started on the device
 CHUNK_FUSE = "chunk_fuse"              # depth decision for a fused dispatch
 DEVICE_SUBMIT = "device_submit"        # handed to the device contract
 DEVICE_COMPLETE = "device_complete"    # device completion (carries dur)
-DEVICE_MEASURED = "device_measured"    # live measured-vs-expected report
 
 # Annotation lanes (never part of a frame's attribution chain).
 ADMISSION = "admission"                # admission verdict for a request
@@ -76,6 +84,21 @@ LATE = "late"                          # finished past its deadline (a miss)
 SHED = "shed"                          # dropped at the gateway / late-rejected
 LOST = "lost"                          # destroyed (wire loss / died with slice)
 TERMINAL_STAGES = frozenset({COMPLETED, LATE, SHED, LOST})
+
+# Profiler spans (``span``), one per stage of the serving loop. The
+# ``job_id`` ones carry the id that ``EDF_DISPATCH`` events carry.
+SPANS = (
+    "deeprt.clock",               # WallClock.run anchor: carries loop_now
+    "deeprt.loop.wait",           # WallClock.run asleep on its condition
+    "deeprt.transport.datagram",  # receive, reassembly, gateway ingest
+    "deeprt.disbatcher.flush",    # a window joint or an early flush
+    "deeprt.admission",           # one admission test
+    "deeprt.edf.dispatch",        # pick through submit (job_id)
+    "deeprt.engine.stage",        # staging-ring fill + device_put (job_id)
+    "deeprt.engine.launch",       # the jitted call's enqueue (job_id)
+    "deeprt.device.wait",         # waiter's block_until_ready (job_id)
+    "deeprt.device.complete",     # completion handling on the loop (job_id)
+)
 
 # Attribution stage names, in budget order.
 ATTR_STAGES = ("wire", "reorder_buffer", "window", "queue", "device", "overrun")
@@ -136,6 +159,9 @@ class FrameTracer:
         }
         # Terminal accounting: stage -> count (conservation mirror).
         self.terminals: Dict[str, int] = {}
+        # ``loop.now`` of the latest ``deeprt.clock`` span (clock_anchor):
+        # that span's start in a profiler trace is this ring time.
+        self.anchor: Optional[float] = None
 
     # -- hot path ----------------------------------------------------------
     def emit(
@@ -280,16 +306,31 @@ class FrameTracer:
             }
         return out
 
-    def frame_spans(self, rid: int, idx: int) -> List[SpanEvent]:
-        """All ring-resident events for one frame, in emit order."""
-        return [e for e in self.ring if e.rid == rid and e.idx == idx]
-
     # -- export ------------------------------------------------------------
-    def chrome_trace(self) -> Dict:
+    def trace_ns(self, t: float, anchor_ns: int) -> float:
+        """Ring time ``t`` on a profiler trace's clock, given the start
+        (ns, as the trace reports it) of the ``deeprt.clock`` span that
+        set ``self.anchor``: both clocks advance at the same rate."""
+        if self.anchor is None:
+            raise ValueError("no deeprt.clock anchor: run a WallClock with "
+                             "this tracer attached")
+        return anchor_ns + (t - self.anchor) * 1e9
+
+    def chrome_trace(self, anchor_ns: Optional[int] = None) -> Dict:
         """The ring as Chrome ``trace_event`` JSON (load in
         ``chrome://tracing`` or Perfetto). Device completions become
         duration ("X") slices spanning their execution; every other
-        event is an instant ("i") on its frame's thread lane."""
+        event is an instant ("i") on its frame's thread lane.
+
+        ``anchor_ns`` (the latest ``deeprt.clock`` span's start in a
+        profiler trace) puts ``ts`` on that trace's clock, so the export
+        lines up with the device's operations; without it ``ts`` is
+        ``loop.now``."""
+        def to_us(t: float) -> float:  # trace_event wants microseconds
+            if anchor_ns is None:
+                return t * 1e6
+            return self.trace_ns(t, anchor_ns) / 1e3
+
         events: List[Dict] = []
         for ev in self.ring:
             args: Dict = {"frame": ev.idx}
@@ -299,7 +340,7 @@ class FrameTracer:
                 args.update(ev.meta)
             rec = {
                 "name": ev.stage,
-                "ts": ev.t * 1e6,  # trace_event wants microseconds
+                "ts": to_us(ev.t),
                 "pid": ev.where or "system",
                 "tid": f"req{ev.rid}" if ev.rid >= 0 else ev.stage,
                 "args": args,
@@ -307,7 +348,7 @@ class FrameTracer:
             dur = ev.meta.get("dur") if ev.meta else None
             if ev.stage == DEVICE_COMPLETE and dur is not None:
                 rec["ph"] = "X"
-                rec["ts"] = (ev.t - dur) * 1e6
+                rec["ts"] = to_us(ev.t - dur)
                 rec["dur"] = dur * 1e6
             else:
                 rec["ph"] = "i"
@@ -327,8 +368,86 @@ class FrameTracer:
             "emitted": self.emitted,
             "evicted": self.evicted,
             "open_frames": len(self._open),
+            "anchor": self.anchor,
             "attribution": self.attribution(),
         }
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans and the device-idle clock
+# ---------------------------------------------------------------------------
+
+NO_SPAN = nullcontext()  # the span of every untraced stage, shared
+
+
+def span(tracer, name: str, job_id: Optional[int] = None):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` while ``tracer``
+    is attached, else the shared ``NO_SPAN``: the off path builds
+    nothing and imports nothing. ``job_id`` rides the span as a stat
+    (the id ``EDF_DISPATCH`` events carry); a traced span entered before
+    its job is known sets it later with ``set_metadata(job_id=...)``."""
+    if tracer is None:
+        return NO_SPAN
+    from jax import profiler
+
+    if job_id is None:
+        return profiler.TraceAnnotation(name)
+    return profiler.TraceAnnotation(name, job_id=job_id)
+
+
+def clock_anchor(tracer, now: float) -> None:
+    """Emit the ``deeprt.clock`` span carrying ``loop_now=now`` and keep
+    ``now`` as the tracer's anchor: in a profiler trace that span starts
+    at ring time ``now`` (``FrameTracer.trace_ns``)."""
+    from jax import profiler
+
+    with profiler.TraceAnnotation("deeprt.clock", loop_now=now):
+        pass
+    tracer.anchor = now
+
+
+# IdleClock states.
+DEVICE_RUNNING = "running"  # a job on the device: submit to waiter wake
+IDLE_HELD = "held"          # nothing queued; frames held in a window
+IDLE_READY = "ready"        # a job queued, or a finished one not yet handled
+IDLE_EMPTY = "empty"        # nothing pending anywhere
+IDLE_STATES = (IDLE_HELD, IDLE_READY, IDLE_EMPTY)
+
+
+class IdleClock:
+    """Seconds a device spends in each state, on the loop's clock.
+
+    The EDF worker owns one and marks it at the few transitions that
+    change the state (frame into a window, flush, EDF submit, device
+    submit, device completion); the live device marks ``ready`` at the
+    instant its waiter saw the job finish, so the lag until the loop
+    handles the completion counts as ``ready``. Time is only ever moved
+    from one state to the next, so the four sums add up to the time
+    since the clock started.
+    """
+
+    __slots__ = ("state", "since", "seconds")
+
+    def __init__(self, now: float = 0.0):
+        self.state = IDLE_EMPTY
+        self.since = now
+        self.seconds = {DEVICE_RUNNING: 0.0, IDLE_HELD: 0.0,
+                        IDLE_READY: 0.0, IDLE_EMPTY: 0.0}
+
+    def mark(self, state: str, now: float) -> None:
+        """Enter ``state`` at ``now`` (a stamp earlier than the current
+        state's start, from the waiter thread, counts from that start)."""
+        if now > self.since:
+            self.seconds[self.state] += now - self.since
+            self.since = now
+        self.state = state
+
+    def totals(self, now: float) -> Dict[str, float]:
+        """Seconds per state, the current one brought up to ``now``."""
+        out = dict(self.seconds)
+        if now > self.since:
+            out[self.state] += now - self.since
+        return out
 
 
 # ---------------------------------------------------------------------------

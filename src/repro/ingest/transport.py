@@ -873,6 +873,10 @@ class TransportServer:
 
     # -- datagram entry --------------------------------------------------
     def datagram(self, data: bytes) -> None:
+        with T.span(self.tracer, "deeprt.transport.datagram"):
+            self._datagram(data)
+
+    def _datagram(self, data: bytes) -> None:
         mtype, msg = decode(data)
         if mtype == MALFORMED:
             self.note_malformed(msg)

@@ -41,7 +41,8 @@ from repro.core import telemetry as T
 class _Inflight:
     """One submitted job travelling from the loop to the waiter and back."""
 
-    __slots__ = ("job", "handle", "on_complete", "job_bytes", "start", "exec_time", "released")
+    __slots__ = ("job", "handle", "on_complete", "job_bytes", "start",
+                 "exec_time", "released", "done")
 
     def __init__(self, job, handle, on_complete, job_bytes, start, exec_time):
         self.job = job
@@ -51,6 +52,7 @@ class _Inflight:
         self.start = start
         self.exec_time = exec_time
         self.released = False
+        self.done = start  # loop time the waiter saw the job finish
 
 
 class AsyncDevice:
@@ -94,11 +96,13 @@ class AsyncDevice:
         # thread; ``on_measured(expected, actual)`` fires per completion.
         self.watchdog = None
         self.on_measured: Optional[Callable[[float, float], None]] = None
-        # Frame-lifecycle tracer (core/telemetry.py); None = off. This
-        # is the live-only expected-vs-measured lane — simulation has no
-        # hardware clock to disagree with.
+        # Frame-lifecycle tracer (core/telemetry.py); None = off. Spans
+        # ``deeprt.device.wait`` (waiter) and ``deeprt.device.complete``.
         self.tracer = None
         self.tracer_tag: Optional[str] = None
+        # The EDF worker's idle clock (wired by DeepRT): marked ``ready``
+        # at the instant the waiter saw a job finish.
+        self.idle_clock: Optional[T.IdleClock] = None
         self._lock = threading.Lock()
         self._inflight: Optional[_Inflight] = None
         self._inbox: "queue.Queue" = queue.Queue()
@@ -157,10 +161,13 @@ class AsyncDevice:
             if item is None:
                 return
             err = None
-            try:
-                item.handle.wait()
-            except Exception as e:  # re-raised on the loop thread
-                err = self.last_error = e
+            with T.span(self.tracer, "deeprt.device.wait",
+                        getattr(item.job, "job_id", None)):
+                try:
+                    item.handle.wait()
+                except Exception as e:  # re-raised on the loop thread
+                    err = self.last_error = e
+            item.done = self.loop.now
             self.loop.post(
                 lambda it=item, x=err: self._complete(it, x),
                 priority=getattr(self.loop, "PRIO_COMPLETE", 1),
@@ -182,17 +189,20 @@ class AsyncDevice:
 
     # ----- loop-thread completion ----------------------------------------
     def _complete(self, item: _Inflight, err: Optional[Exception] = None) -> None:
+        with T.span(self.tracer, "deeprt.device.complete",
+                    getattr(item.job, "job_id", None)):
+            self._finish(item, err)
+
+    def _finish(self, item: _Inflight, err: Optional[Exception]) -> None:
         now = self.loop.now
         actual = now - item.start
         self.busy_time += actual
         self._busy_until = None
         self.resident_bytes -= item.job_bytes
+        if self.idle_clock is not None:
+            self.idle_clock.mark(T.IDLE_READY, item.done)
         if self.watchdog is not None:
             self.watchdog.completed()
-        if self.tracer is not None:
-            self.tracer.emit(
-                T.DEVICE_MEASURED, now, where=self.tracer_tag,
-                meta={"expected": item.exec_time, "actual": actual})
         if self._closed:
             # The slice died while this job was in flight: its frames are
             # lost with the slice (the cluster re-admits the request's

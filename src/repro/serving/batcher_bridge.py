@@ -271,6 +271,7 @@ def _wire_live_scheduler(
     def dispatch_job(job):
         mid, shape = job.category.model_id, job.shape_key
         kind = kind_of(job)
+        engine.job_id = job.job_id  # tags the engine's profiler spans
         if isinstance(job, ChunkJob):
             # A fused k-step decode chunk: ONE scanned dispatch, with
             # each member job's payload staged as its own step (one
@@ -404,6 +405,7 @@ def build_live_scheduler(
     )
     if tracer is not None:
         sched.attach_tracer(tracer)
+        engine.tracer = tracer
     return sched, engine, table
 
 

@@ -67,6 +67,7 @@ larger than ``max_slots`` are rejected loudly rather than re-shaped.
 from __future__ import annotations
 
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -75,20 +76,51 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import telemetry as T
 from repro.core.bucketing import bucket
 from repro.ingest.staging import StagingRing, check_payload_dtype
 from repro.models import model_for
 from repro.models.kvcache import cache_nbytes, cache_reset_rows
 
 
-def prefill_program(model):
-    """The jitted prefill step: (params, tokens (B, S)) -> next tokens."""
+# Every XLA compile in the process (a persistent-cache load included):
+# one ``jax.monitoring`` listener, registered with the first engine,
+# counts each into the ``xla_compiles`` / ``xla_compile_s`` stats of
+# every engine not frozen — the arena's row release and eager ops too,
+# which the per-program counters never see.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_ENGINES: "weakref.WeakSet[InferenceEngine]" = weakref.WeakSet()
+_listening = False
 
-    def run(params, tokens):
+
+def _count_compile(event: str, secs: float, **_kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    for engine in list(_ENGINES):
+        if engine.frozen:
+            continue  # a failed slice's counters stay as it left them
+        engine.stats["xla_compiles"] += 1
+        engine.stats["xla_compile_s"] += secs
+
+
+def _watch_compiles(engine: "InferenceEngine") -> None:
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+        _listening = True
+    _ENGINES.add(engine)
+
+
+def prefill_program(model):
+    """The jitted prefill step: (params, tokens (B, S)) -> next tokens.
+    The function's name is the program's name in a device trace
+    (``jit_run_prefill``)."""
+
+    def run_prefill(params, tokens):
         logits, _ = model.forward(params, tokens)
         return logits[:, -1].argmax(-1)
 
-    return jax.jit(run)
+    return jax.jit(run_prefill)
 
 
 def decode_program(model, seq: int, donate: bool):
@@ -96,14 +128,14 @@ def decode_program(model, seq: int, donate: bool):
     (params, cache, tok, cur, active) -> (logits, new cache, new cur).
     ``donate`` donates the cache, so the arena updates in place."""
 
-    def run(params, cache, tok, cur, active):
+    def run_decode(params, cache, tok, cur, active):
         logits, new_cache = model.decode_step(
             params, cache, tok, cur, active=active
         )
         new_cur = jnp.where(active, jnp.minimum(cur + 1, seq - 1), cur)
         return logits, new_cache, new_cur
 
-    return jax.jit(run, donate_argnums=(1,) if donate else ())
+    return jax.jit(run_decode, donate_argnums=(1,) if donate else ())
 
 
 @dataclass
@@ -228,8 +260,15 @@ class InferenceEngine:
         # nothing may touch this engine's arenas again.
         self.frozen = False
         # Measured padding/compile accounting.
-        self.stats: Dict[str, int] = {}
+        self.stats: Dict[str, float] = {}
         self.reset_stats()
+        _watch_compiles(self)
+        # Frame-lifecycle tracer (core/telemetry.py); None = off. With it,
+        # each dispatch opens ``deeprt.engine.stage`` / ``.launch`` spans
+        # tagged with ``job_id``, the id of the job being dispatched
+        # (set by the live bridge before each dispatch).
+        self.tracer = None
+        self.job_id: Optional[int] = None
 
     def _put(self, x):
         """Commit ``x`` (array or pytree) to this engine's device."""
@@ -245,11 +284,14 @@ class InferenceEngine:
         """Zero the padding/dispatch/compile counters. build_live_scheduler
         calls this after the offline profiling pass so ``stats`` reflects
         only served traffic — in particular ``decode_compiles`` counts
-        programs built AFTER warm-up, which the slot arena holds at 0."""
+        programs built AFTER warm-up, which the slot arena holds at 0.
+        ``real_rows`` counts rows that carry a frame (a decode step's
+        token-bearing ``step_rows``); ``xla_compiles``/``xla_compile_s``
+        every XLA compile in the process (``_count_compile``)."""
         self.stats.update(
             real_rows=0, bucket_rows=0, real_slots=0, total_slots=0,
             dispatches=0, decode_compiles=0, prefill_compiles=0,
-            chunk_steps=0,
+            chunk_steps=0, xla_compiles=0, xla_compile_s=0.0,
         )
 
     def freeze(self) -> None:
@@ -314,7 +356,7 @@ class InferenceEngine:
             self.stats["decode_compiles"] += 1
             model = self.models[mid]
 
-            def run(params, cache, toks, cur, active, masks):
+            def run_decode_chunk(params, cache, toks, cur, active, masks):
                 def body(carry, xs):
                     cache, cur = carry
                     tok, mask = xs
@@ -333,7 +375,7 @@ class InferenceEngine:
                 return logits, new_cache, new_cur
 
             donate = (1,) if self.donate_cache else ()
-            self._compiled[key] = jax.jit(run, donate_argnums=donate)
+            self._compiled[key] = jax.jit(run_decode_chunk, donate_argnums=donate)
         return self._compiled[key]
 
     # ----- slot arena ------------------------------------------------------
@@ -596,8 +638,10 @@ class InferenceEngine:
             self.stats["bucket_rows"] += b
             fn = self._prefill_fn(mid, seq, b)
             ring = self.staging_ring("prefill", mid, seq, b)
-            tokens = self._stage_prefill_tokens(ring, payload, batch_size)
-            out = fn(self.params[mid], tokens)
+            with T.span(self.tracer, "deeprt.engine.stage", self.job_id):
+                tokens = self._stage_prefill_tokens(ring, payload, batch_size)
+            with T.span(self.tracer, "deeprt.engine.launch", self.job_id):
+                out = fn(self.params[mid], tokens)
             handle = StepHandle(out, mid, kind, batch_size, b)
             # The handle's wait guards this scratch buffer's reuse: the
             # ring refills it only after this step finished reading it
@@ -613,9 +657,11 @@ class InferenceEngine:
         arena = self.arena(mid, seq)
         fn = self._decode_fn(mid, seq)
         ring = self.staging_ring("decode", mid, seq, m)
-        tok = self._stage_decode_tokens(
-            ring, payload, prefix_rows=batch_size if slots is None else None
-        )
+        with T.span(self.tracer, "deeprt.engine.stage", self.job_id):
+            tok = self._stage_decode_tokens(
+                ring, payload, prefix_rows=batch_size if slots is None else None
+            )
+        rows = batch_size  # rows that carry a token this step
         if slots is None:
             if len(arena.free) != arena.max_slots:
                 raise ValueError(
@@ -645,14 +691,16 @@ class InferenceEngine:
                         f"step_rows {extra} are not live rows {sorted(ids)}"
                     )
                 active = arena.active & self._row_mask(step)
+                rows = len(set(step))
         k = batch_size if self.masked_decode else m
-        self.stats["real_rows"] += batch_size
+        self.stats["real_rows"] += rows
         self.stats["bucket_rows"] += m
         self.stats["real_slots"] += batch_size * seq
         self.stats["total_slots"] += k * seq
-        logits, new_cache, new_cur = fn(
-            self.params[mid], arena.cache, tok, cur, active
-        )
+        with T.span(self.tracer, "deeprt.engine.launch", self.job_id):
+            logits, new_cache, new_cur = fn(
+                self.params[mid], arena.cache, tok, cur, active
+            )
         # The arena pytree is REPLACED every step (with donation the new
         # leaves alias the old buffers — in-place; without, XLA copied).
         arena.cache = new_cache
@@ -766,25 +814,32 @@ class InferenceEngine:
 
         staged = []
         prefix = batch_size if slots is None else None
-        for i in range(k):
-            payload_i = payloads[i] if payloads is not None else None
-            staged.append(
-                self._stage_decode_tokens(ring, payload_i, prefix_rows=prefix)
-            )
-            ring.attach_consumer(_chunk_guard)
-        toks = jnp.stack(staged)
-        masks = self._step_masks(k, step_rows)
+        with T.span(self.tracer, "deeprt.engine.stage", self.job_id):
+            for i in range(k):
+                payload_i = payloads[i] if payloads is not None else None
+                staged.append(
+                    self._stage_decode_tokens(ring, payload_i, prefix_rows=prefix)
+                )
+                ring.attach_consumer(_chunk_guard)
+            toks = jnp.stack(staged)
+            masks = self._step_masks(k, step_rows)
         fn = self._decode_chunk_fn(mid, seq, k)
         kk = batch_size if self.masked_decode else m
+        # Rows that carry a token, summed over the chunk's steps.
+        rows = batch_size * k
+        if step_rows is not None:
+            rows = sum(batch_size if r is None else len({int(s) for s in r})
+                       for r in step_rows)
         self.stats["dispatches"] += 1
         self.stats["chunk_steps"] += k
-        self.stats["real_rows"] += batch_size * k
+        self.stats["real_rows"] += rows
         self.stats["bucket_rows"] += m * k
         self.stats["real_slots"] += batch_size * seq * k
         self.stats["total_slots"] += kk * seq * k
-        logits, new_cache, new_cur = fn(
-            self.params[mid], arena.cache, toks, cur, active, masks
-        )
+        with T.span(self.tracer, "deeprt.engine.launch", self.job_id):
+            logits, new_cache, new_cur = fn(
+                self.params[mid], arena.cache, toks, cur, active, masks
+            )
         arena.cache = new_cache
         if slots is not None:
             arena.cur = new_cur

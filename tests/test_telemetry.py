@@ -496,3 +496,377 @@ class TestCappedLogs:
             cluster.submit_request(req)
         assert len(cluster.placement_attempts) == 8
         assert cluster.placement_attempts_overflow == 5
+
+
+# ---------------------------------------------------------------------------
+# Device-idle split, loop lag, engine counters, profiler spans
+# ---------------------------------------------------------------------------
+def _idle(sched, now):
+    t = sched.worker.idle_clock.totals(now)
+    return t[T.IDLE_HELD], t[T.IDLE_READY], t[T.IDLE_EMPTY], t[T.DEVICE_RUNNING]
+
+
+def _live(tracer=None):
+    """A WallClock DeepRT over an AsyncDevice whose jobs finish at once."""
+    loop = WallClock()
+    sched = DeepRT(_table(), loop=loop,
+                   device=AsyncDevice(loop, lambda job: _InstantHandle()))
+    if tracer is not None:
+        sched.attach_tracer(tracer, tag="s0")
+    return sched
+
+
+def _serve_live(sched, n_frames=6, seconds=0.8):
+    req = Request(category=CAT, period=0.08, n_frames=n_frames,
+                  relative_deadline=0.3)
+    assert sched.submit_request(req).admitted
+    sched.loop.run(until=sched.loop.now + seconds)
+
+
+class TestIdleClock:
+    def test_split_fills_the_window_minus_busy_on_the_sim(self):
+        sched = DeepRT(_table())
+        req = Request(category=CAT, period=0.15, n_frames=12,
+                      relative_deadline=0.4)
+        assert sched.submit_request(req).admitted
+        sched.run(until=5.0)
+        held, ready, empty, running = _idle(sched, 5.0)
+        busy = sched.device.busy_time
+        assert sched.metrics.completed_frames == 12
+        assert held + ready + empty == pytest.approx(5.0 - busy, abs=1e-9)
+        assert running == pytest.approx(busy, abs=1e-9)
+        assert held > 0 and empty > 0
+
+    def test_frame_refused_by_the_early_flush_guard_is_held(self):
+        # b = 1 WCET 0.05 s, window 0.1 s: joints at 0.1, 0.2, ... The
+        # frame lands at 0.06; 0.06 + 0.05 passes the joint at 0.1, so
+        # the guard refuses the early flush and the frame waits for it.
+        sched = DeepRT(_table())
+        req = Request(category=CAT, period=1.0, n_frames=1,
+                      relative_deadline=0.2, start_time=0.06)
+        assert sched.submit_request(req).admitted
+        sched.run(until=1.0)
+        held, ready, empty, running = _idle(sched, 1.0)
+        assert sched.metrics.completed_frames == 1
+        assert held == pytest.approx(0.04, abs=1e-9)
+        assert ready == 0.0
+        assert running == pytest.approx(sched.device.busy_time, abs=1e-9)
+        assert held + empty + running == pytest.approx(1.0, abs=1e-9)
+
+    def test_live_completion_lag_counts_as_ready(self):
+        sched = _live()
+        start = sched.worker.idle_clock.since
+        _serve_live(sched)
+        now = sched.loop.now
+        held, ready, empty, running = _idle(sched, now)
+        assert sched.metrics.completed_frames == 6
+        assert ready > 0
+        assert held + ready + empty + running == pytest.approx(now - start, abs=1e-9)
+
+    def test_aggregate_metrics_sum_the_split_over_slices(self):
+        cluster = build_sim_cluster(_table, ("s0", "s1"))
+        for _ in range(2):
+            req = Request(category=CAT, period=0.1, n_frames=10,
+                          relative_deadline=0.5)
+            assert cluster.submit_request(req)
+        cluster.run(until=3.0)
+        agg = cluster.aggregate_metrics()
+        split = (agg["device_busy_s"] + agg["device_idle_held_s"]
+                 + agg["device_idle_ready_s"] + agg["device_idle_empty_s"])
+        assert split == pytest.approx(2 * 3.0, abs=1e-9)
+        assert agg["dispatches"] == agg["jobs"] > 0
+        assert agg["dispatch_host_s"] > 0
+        # the virtual loop runs every callback on time
+        assert agg["loop_late_s"] == 0.0
+
+
+def _counterless_window(tracer, until, **stats):
+    """A benchmark window as a program without the idle split, loop lag,
+    dispatch host time and compile counters leaves it."""
+    from types import SimpleNamespace
+
+    subs = [ev.t for ev in tracer.ring if ev.stage == T.DEVICE_SUBMIT]
+    table = {"agg": {"open": {}, "close": {}},
+             "stats": {"open": {k: 0 for k in stats}, "close": stats}}
+    return SimpleNamespace(
+        seconds=until, tracer_events=list(tracer.ring),
+        recorder=SimpleNamespace(
+            window=(0.0, until), decode=[],
+            prefill=[SimpleNamespace(t=t - 0.002) for t in subs]),
+        agg=table["agg"], stats=table["stats"],
+        extra={"lateness": [0.001, 0.003]},
+        delta=lambda t, k: table[t]["close"][k] - table[t]["open"][k])
+
+
+class TestBenchReadersWithoutCounters:
+    """The benchmark's readers of these counters read a program that
+    lacks them from its ring, the check's recorder and the engine's own
+    compile counts, and still give a number."""
+
+    @pytest.mark.parametrize("start", [0.0, 0.06])
+    def test_ring_split_matches_the_idle_clock_on_the_sim(self, start):
+        from bench.metrics.idle_held_share import ring_split
+
+        tracer = FrameTracer()
+        sched = DeepRT(_table())
+        sched.attach_tracer(tracer)
+        req = Request(category=CAT, period=0.15, n_frames=12,
+                      relative_deadline=0.4, start_time=start)
+        assert sched.submit_request(req).admitted
+        sched.run(until=5.0)
+        held, ready, _, _ = _idle(sched, 5.0)
+        split = ring_split(_counterless_window(tracer, 5.0))
+        assert held > 0
+        assert split["held"] == pytest.approx(held, abs=1e-9)
+        assert split["ready"] == pytest.approx(ready, abs=1e-9)
+
+    def test_every_reader_gives_a_finite_number(self):
+        from bench.harness import metric_reader
+
+        tracer = FrameTracer()
+        sched = DeepRT(_table())
+        sched.attach_tracer(tracer)
+        req = Request(category=CAT, period=0.15, n_frames=12,
+                      relative_deadline=0.4)
+        assert sched.submit_request(req).admitted
+        sched.run(until=5.0)
+        win = _counterless_window(tracer, 5.0, prefill_compiles=2,
+                                  decode_compiles=1)
+        read = {n: metric_reader(n)(win) for n in (
+            "idle_held_share", "idle_ready_share", "dispatch_host_us",
+            "loop_lag_ms", "window_compiles")}
+        assert all(isinstance(v, float) and math.isfinite(v)
+                   for v in read.values()), read
+        assert read["idle_held_share"] > 0
+        assert read["dispatch_host_us"] == pytest.approx(2000.0)
+        assert read["loop_lag_ms"] == pytest.approx(2.0)
+        assert read["window_compiles"] == 3.0
+
+
+class TestLoopLag:
+    def test_a_sleeping_callback_makes_the_next_one_late(self):
+        import time
+
+        loop = WallClock()
+        at = loop.now + 0.01
+        loop.schedule(at, lambda: time.sleep(0.2))
+        loop.schedule(at, lambda: None)
+        loop.run()
+        assert loop.loop_callbacks == 2
+        assert loop.loop_late_s >= 0.2
+
+
+ENG_MID = "granite-3-2b"
+
+
+def _engine(**kw):
+    from repro.configs.registry import tiny
+    from repro.serving.engine import InferenceEngine
+
+    return InferenceEngine({ENG_MID: tiny(ENG_MID)}, **kw)
+
+
+class TestEngineCounters:
+    def test_new_prefill_bucket_and_row_release_count_as_compiles(self):
+        e = _engine(max_slots=5)
+        n0 = e.stats["xla_compiles"]
+        e.execute(ENG_MID, (24,), 3, "prefill")  # bucket 4: first use
+        n1 = e.stats["xla_compiles"]
+        assert n1 > n0 and e.stats["xla_compile_s"] > 0
+        programs = (e.stats["prefill_compiles"], e.stats["decode_compiles"])
+        # The arena's first row release compiles; the engine's per-program
+        # counters do not see it.
+        e.alloc_slots(ENG_MID, 40, 2)
+        assert e.stats["xla_compiles"] > n1
+        assert (e.stats["prefill_compiles"], e.stats["decode_compiles"]) == programs
+
+    def test_served_programs_have_their_own_names(self):
+        import jax
+        import jax.numpy as jnp
+
+        from bench.trace import PROGRAM_PREFIX
+
+        seq, k = 16, 2
+        e = _engine(max_slots=2, chunk_depth=k)
+        params = e.params[ENG_MID]
+        arena = e.arena(ENG_MID, seq)
+        m = e.max_slots
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        lowered = {
+            "jit_run_prefill": e._prefill_fn(ENG_MID, seq, 1).lower(
+                params, spec((1, seq), jnp.int32)),
+            "jit_run_decode": e._decode_fn(ENG_MID, seq).lower(
+                params, arena.cache, spec((m,), jnp.int32), arena.cur,
+                arena.active),
+            "jit_run_decode_chunk": e._decode_chunk_fn(ENG_MID, seq, k).lower(
+                params, arena.cache, spec((k, m), jnp.int32), arena.cur,
+                arena.active, spec((k, m), jnp.bool_)),
+        }
+        for name, low in lowered.items():
+            assert f"module @{name} " in low.as_text(), name
+            # the benchmark's program-time reader still matches each
+            assert name.startswith(PROGRAM_PREFIX["tpu"])
+
+    def test_decode_real_rows_count_token_rows(self):
+        from types import SimpleNamespace
+
+        from bench import check as C
+        from bench.harness import metric_reader
+
+        seq = 16
+        e = _engine(max_slots=4)
+        rec = C.Recorder(e, SimpleNamespace(now=0.0), seed=1,
+                         window=(-math.inf, math.inf))
+        slots = e.alloc_slots(ENG_MID, seq, 3)
+        e.reset_stats()
+        e.dispatch(ENG_MID, (seq,), 3, "decode", slots=slots,
+                   payload={slots[0]: 5}, step_rows=[slots[0]]).wait()
+        # two frames of one stream in a window: its row still steps once
+        e.dispatch(ENG_MID, (seq,), 3, "decode", slots=slots,
+                   step_rows=[slots[0], slots[2], slots[2]]).wait()
+        e.dispatch(ENG_MID, (seq,), 3, "decode", slots=slots).wait()
+        rec.close()
+        assert e.stats["real_rows"] == 1 + 2 + 3
+        batch_rows = metric_reader("batch_rows")(SimpleNamespace(recorder=rec))
+        assert e.stats["real_rows"] / e.stats["dispatches"] == batch_rows
+
+    def test_prefill_rehearsal_real_rows_match_batch_rows(self):
+        """The benchmark's CPU rehearsal of the prefill cell: per window
+        dispatch, the engine's ``real_rows`` step equals the rows the
+        benchmark's recorder saw, so both give the same ``batch_rows``."""
+        from repro.configs.registry import tiny
+
+        from bench import harness
+        from bench.tests.rehearse import CPU_PEAKS, CPU_SLOWDOWN, SEED
+
+        cell = harness.load_cell("granite-prefill-camera")
+        streams = [dict(g, period_s=g["period_s"] * CPU_SLOWDOWN,
+                        deadline_s=g["deadline_s"] * CPU_SLOWDOWN)
+                   for g in cell.mix["streams"]]
+        cell.mix = dict(cell.mix, warmup_s=1.0, streams=streams)
+        steps = []
+
+        def count_rows(stack):
+            engine, loop = stack.engine, stack.cluster.loop
+            inner = engine.dispatch
+
+            def dispatch(*args, **kw):
+                before = engine.stats["real_rows"]
+                handle = inner(*args, **kw)
+                steps.append((loop.now, engine.stats["real_rows"] - before))
+                return handle
+
+            engine.dispatch = dispatch
+
+        stack = harness.build(cell, False, tiny(cell.config["program_arch"]),
+                              CPU_PEAKS)
+        try:
+            win = harness.serve(stack, cell.mix, SEED, 2.0, False,
+                                fault=count_rows)
+        finally:
+            stack.close()
+        inside = [rows for t, rows in steps if win.recorder.in_window(t)]
+        assert inside and len(inside) == len(win.recorder.prefill)
+        assert sum(inside) / len(inside) == \
+            harness.metric_reader("batch_rows")(win)
+
+
+def _profiled_spans(tmp_path, run):
+    """Run ``run()`` under a CPU ``jax.profiler`` trace; return the host
+    spans named ``deeprt.*`` as (name, start_ns, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("deeprt."):
+                    spans.append((ev.name, ev.start_ns, dict(ev.stats)))
+    return spans
+
+
+class TestProfilerSpans:
+    LOOP_SPANS = {"deeprt.clock", "deeprt.loop.wait", "deeprt.admission",
+                  "deeprt.disbatcher.flush", "deeprt.edf.dispatch",
+                  "deeprt.device.wait", "deeprt.device.complete"}
+
+    def test_spans_follow_the_tracer(self, tmp_path):
+        tracer = FrameTracer()
+        traced = _profiled_spans(tmp_path / "on",
+                                 lambda: _serve_live(_live(tracer)))
+        assert {n for n, _s, _m in traced} >= self.LOOP_SPANS
+        assert {n for n, _s, _m in traced} <= set(T.SPANS)
+        jobs = {ev.meta["job_id"] for ev in tracer.ring
+                if ev.stage == T.EDF_DISPATCH}
+        for name in ("deeprt.edf.dispatch", "deeprt.device.wait",
+                     "deeprt.device.complete"):
+            assert {m["job_id"] for n, _s, m in traced if n == name} == jobs
+        untraced = _profiled_spans(tmp_path / "off",
+                                   lambda: _serve_live(_live()))
+        assert untraced == []
+
+    def test_engine_and_transport_spans(self, tmp_path):
+        from repro.ingest.transport import TransportServer
+
+        tracer = FrameTracer()
+        e = _engine(max_slots=2)
+        e.tracer, e.job_id = tracer, 7
+        transport = TransportServer(IngestGateway(build_sim_cluster(_table, ("s0",))))
+        transport.tracer = tracer
+
+        def run():
+            e.execute(ENG_MID, (16,), 1, "prefill")
+            transport.datagram(b"not a datagram")
+
+        spans = _profiled_spans(tmp_path, run)
+        got = {(n, m.get("job_id")) for n, _s, m in spans}
+        assert got == {("deeprt.engine.stage", 7), ("deeprt.engine.launch", 7),
+                       ("deeprt.transport.datagram", None)}
+
+    def test_clock_anchor_maps_ring_times_onto_span_starts(self, tmp_path):
+        tracer = FrameTracer()
+        sched = _live(tracer)
+        spans = _profiled_spans(tmp_path, lambda: _serve_live(sched, n_frames=8))
+        anchors = [(s, m["loop_now"]) for n, s, m in spans if n == "deeprt.clock"]
+        assert tracer.snapshot()["anchor"] == tracer.anchor == anchors[-1][1]
+        anchor_ns = anchors[-1][0]
+        flush = {m["job_id"]: s for n, s, m in spans
+                 if n == "deeprt.disbatcher.flush"}
+        closes = {ev.meta["job_id"]: ev.t for ev in tracer.ring
+                  if ev.stage == T.WINDOW_CLOSE}
+        assert closes and set(closes) == set(flush)
+        errors = sorted(abs(tracer.trace_ns(t, anchor_ns) - flush[j])
+                        for j, t in closes.items())
+        assert errors[len(errors) // 2] < 1e5  # ns: 0.1 ms
+        doc = tracer.chrome_trace(anchor_ns=anchor_ns)
+        first = tracer.ring[0]
+        assert doc["traceEvents"][0]["ts"] == pytest.approx(
+            tracer.trace_ns(first.t, anchor_ns) / 1e3)
+
+    def test_untraced_hot_path_builds_no_annotation(self, monkeypatch):
+        import jax.profiler
+
+        built = []
+
+        class Counting(jax.profiler.TraceAnnotation):
+            def __init__(self, name, **meta):
+                built.append(name)
+                super().__init__(name, **meta)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        _serve_live(_live())
+        assert built == []
+        _serve_live(_live(FrameTracer()))
+        assert set(built) >= self.LOOP_SPANS
