@@ -96,6 +96,9 @@ def one_chip(cfg, clock):
     log(f"device busy (host clock, AsyncDevice): {sl.device.busy_time:.3f}s "
         f"of {served.serve_seconds:.3f}s served")
     eng = sl.engine
+    log(f"prefill programs: {eng.stats['prefill_tail_one_row']} with the "
+        f"last block on one row, {eng.stats['prefill_unembed_only']} with "
+        f"only the unembedding cut")
     mid, seq = cfg.arch_id, stack.traffic.decode_seq
     params_b = nbytes(eng.params)
     arena_b = eng.arena_nbytes(mid, seq)

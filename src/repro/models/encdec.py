@@ -84,6 +84,9 @@ class EncDecTransformer:
     """Whisper-family model. cfg.n_layers = decoder layers,
     cfg.n_encoder_layers = encoder layers."""
 
+    # ``next_token_logits`` computes every decoder row (no cut).
+    prefill_cut = None
+
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.MAX_DEC_POSITIONS = cfg.max_dec_positions
@@ -190,6 +193,10 @@ class EncDecTransformer:
         x, _ = jax.lax.scan(body, x, params["decoder"])
         x = apply_norm(x, params["dec_final_norm"], cfg.norm)
         return unembed(x, params["embed"]), jnp.zeros((), jnp.float32)
+
+    def next_token_logits(self, params, frames, dec_tokens) -> jax.Array:
+        """(B, V) f32 logits of the token after ``dec_tokens``."""
+        return self.forward(params, frames, dec_tokens)[0][:, -1]
 
     def loss(self, params, frames, dec_tokens, aux_weight: float = 0.0):
         logits, _ = self.forward(params, frames, dec_tokens)

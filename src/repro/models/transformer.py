@@ -7,6 +7,8 @@ size stay flat in depth). Non-divisible tail layers run unscanned.
 
 Modes:
 - ``forward``      — full-sequence logits (training / prefill shapes)
+- ``next_token_logits`` — the last position's logits only (the serving
+                     engine's prefill program)
 - ``loss``         — next-token cross entropy (+ MoE aux)
 - ``prefill``      — forward + populate decode caches
 - ``decode_step``  — one token against caches (serve_step for the
@@ -183,6 +185,52 @@ def _apply_block_full(
     return x, aux, contrib
 
 
+def _apply_block_last_row(
+    cfg: ModelConfig,
+    kind: str,
+    p: Dict,
+    x: jax.Array,  # (B, S, D)
+    positions: jax.Array,
+) -> jax.Array:
+    """A dense attention block read at the last position only: ``norm1``
+    and the rotated K/V run over all S positions, the query, attention,
+    output projection and MLP on row S-1. Returns (B, 1, D), equal to
+    ``_apply_block_full(...)[0][:, -1:]``."""
+    window = cfg.sliding_window if kind == "swa" else None
+    pos1d = positions if positions.ndim == 2 else positions[0]
+    mrope = cfg.rope_kind == "mrope"
+    b, s, d = x.shape
+
+    def last(a):  # (B, S, D) -> (B, 1, D)
+        # A strided slice of the flattened rows: ``a[:, -1:]`` makes XLA
+        # on the TPU relayout the whole hidden state (and V) at B >= 2.
+        return a.reshape(b * s, d)[s - 1 :: s][:, None]
+
+    h = apply_norm(x, p["norm1"], cfg.norm)
+    k, v = project_kv(
+        p["mixer"], h, positions if mrope else pos1d, cfg.rope_theta,
+        cfg.rope_kind,
+    )
+    # One query row against S keys: the decode path's dense attention.
+    y = mha_decode(
+        p["mixer"],
+        last(h),
+        pos1d[:, -1],
+        k,
+        v,
+        pos1d,
+        None,
+        window=window,
+        rope_theta=cfg.rope_theta,
+        rope_kind=cfg.rope_kind,
+        mrope_position=positions[..., -1:] if mrope else None,
+        impl="xla",
+    )
+    x = last(x) + y
+    h2 = apply_norm(x, p["norm2"], cfg.norm)
+    return x + apply_mlp(h2, p["ffn"], cfg.activation)
+
+
 # ---------------------------------------------------------------------------
 # Decode-step block application
 # ---------------------------------------------------------------------------
@@ -347,6 +395,15 @@ class Transformer:
             x = x * math.sqrt(self.cfg.d_model)
         return constrain(x, ("batch", "seq", "embed"))
 
+    def _unembed(self, params, x):
+        """(B, S, D) final-normed hidden states -> (B, S, V) f32 logits."""
+        if self.cfg.tie_embeddings:
+            return unembed(x, params["embed"])
+        return jnp.einsum(
+            "bsd,dv->bsv", x.astype(jnp.float32),
+            params["lm_head"].astype(jnp.float32),
+        )
+
     def forward(
         self, params, tokens: jax.Array, positions: Optional[jax.Array] = None
     ) -> Tuple[jax.Array, jax.Array]:
@@ -380,14 +437,68 @@ class Transformer:
             x, a, _ = _apply_block_full(cfg, kind, p_layer, x, positions, False)
             aux_total = aux_total + a
         x = apply_norm(x, params["final_norm"], cfg.norm)
-        if cfg.tie_embeddings:
-            logits = unembed(x, params["embed"])
-        else:
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x.astype(jnp.float32),
-                params["lm_head"].astype(jnp.float32),
-            )
+        logits = self._unembed(params, x)
         return logits, aux_total
+
+    @property
+    def prefill_cut(self) -> str:
+        """What ``next_token_logits`` skips: ``"tail_one_row"`` (the last
+        block after its K/V, the final norm and the unembedding run on
+        one row) where the last layer is dense attention;
+        ``"unembed_only"`` where it is MoE (capacity depends on the token
+        count) or recurrent (state and token shift run over the
+        sequence), so only the final norm and the unembedding are cut."""
+        dense_attn = self.cfg.pattern_layers[-1] in ("attn", "swa")
+        if dense_attn and not self.cfg.is_moe:
+            return "tail_one_row"
+        return "unembed_only"
+
+    def next_token_logits(
+        self, params, tokens: jax.Array, positions: Optional[jax.Array] = None
+    ) -> jax.Array:
+        """(B, V) f32 logits of the token after ``tokens``: equal to
+        ``forward(...)[0][:, -1]``, computing only the rows it reads
+        (``prefill_cut``). The layer stack is indexed inside the scan and
+        the last layer taken by a static index, so no slice of the
+        stacked weights is copied."""
+        cfg = self.cfg
+        b, s = tokens.shape
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        x = self._embed(params, tokens)
+
+        def block(kind, p, x):
+            return _apply_block_full(cfg, kind, p, x, positions, False)[0]
+
+        # Whole superblocks, up to the one holding the last layer when
+        # there is no tail.
+        n_scan = cfg.n_super - (0 if cfg.tail_kinds else 1)
+        if n_scan > 0:
+
+            def superblock(x, i):
+                layer_params = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                    params["super"],
+                )
+                for j, kind in enumerate(cfg.block_pattern):
+                    x = block(kind, layer_params[j], x)
+                return x, None
+
+            x, _ = jax.lax.scan(superblock, x, jnp.arange(n_scan))
+        if cfg.tail_kinds:
+            rest = list(zip(cfg.tail_kinds, params["tail"]))
+        else:
+            last = jax.tree.map(lambda a: a[cfg.n_super - 1], params["super"])
+            rest = list(zip(cfg.block_pattern, last))
+        for kind, p in rest[:-1]:
+            x = block(kind, p, x)
+        kind, p = rest[-1]
+        if self.prefill_cut == "tail_one_row":
+            x = _apply_block_last_row(cfg, kind, p, x, positions)
+        else:
+            x = block(kind, p, x)[:, -1:]
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        return self._unembed(params, x)[:, 0]
 
     # ----- loss -----------------------------------------------------------
     def loss(self, params, tokens, positions=None, aux_weight: float = 0.01):
@@ -449,13 +560,7 @@ class Transformer:
             new_tail.append(c2)
         new_cache["tail"] = new_tail
         x = apply_norm(x, params["final_norm"], cfg.norm)
-        if cfg.tie_embeddings:
-            logits = unembed(x, params["embed"])
-        else:
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x.astype(jnp.float32),
-                params["lm_head"].astype(jnp.float32),
-            )
+        logits = self._unembed(params, x)
         return logits[:, 0], new_cache
 
     # ----- prefill (forward + cache population) ----------------------------
@@ -518,11 +623,5 @@ class Transformer:
             new_tail.append(merge(kind, c, contrib))
         new_cache["tail"] = new_tail
         x = apply_norm(x[:, -1:], params["final_norm"], cfg.norm)
-        if cfg.tie_embeddings:
-            logits = unembed(x, params["embed"])
-        else:
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x.astype(jnp.float32),
-                params["lm_head"].astype(jnp.float32),
-            )
+        logits = self._unembed(params, x)
         return logits[:, 0], new_cache

@@ -113,12 +113,12 @@ def _watch_compiles(engine: "InferenceEngine") -> None:
 
 def prefill_program(model):
     """The jitted prefill step: (params, tokens (B, S)) -> next tokens.
-    The function's name is the program's name in a device trace
-    (``jit_run_prefill``)."""
+    It computes only the rows the next token reads
+    (``Transformer.next_token_logits``). The function's name is the
+    program's name in a device trace (``jit_run_prefill``)."""
 
     def run_prefill(params, tokens):
-        logits, _ = model.forward(params, tokens)
-        return logits[:, -1].argmax(-1)
+        return model.next_token_logits(params, tokens).argmax(-1)
 
     return jax.jit(run_prefill)
 
@@ -260,7 +260,12 @@ class InferenceEngine:
         # nothing may touch this engine's arenas again.
         self.frozen = False
         # Measured padding/compile accounting.
-        self.stats: Dict[str, float] = {}
+        # ``prefill_tail_one_row`` / ``prefill_unembed_only`` count the
+        # prefill programs built under each ``prefill_cut``; they describe
+        # the programs the engine holds, so ``reset_stats`` keeps them.
+        self.stats: Dict[str, float] = {
+            "prefill_tail_one_row": 0, "prefill_unembed_only": 0,
+        }
         self.reset_stats()
         _watch_compiles(self)
         # Frame-lifecycle tracer (core/telemetry.py); None = off. With it,
@@ -318,7 +323,10 @@ class InferenceEngine:
         key = ("prefill", mid, seq, batch)
         if key not in self._compiled:
             self.stats["prefill_compiles"] += 1
-            self._compiled[key] = prefill_program(self.models[mid])
+            model = self.models[mid]
+            if model.prefill_cut:
+                self.stats[f"prefill_{model.prefill_cut}"] += 1
+            self._compiled[key] = prefill_program(model)
         return self._compiled[key]
 
     def _decode_fn(self, mid: str, seq: int):

@@ -9,6 +9,7 @@ one process may load the TPU library, and every xdist worker imports
 this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,11 +90,35 @@ def test_engine_decode_program_compiles(one_chip, impl, monkeypatch):
     assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
 
 
-def test_engine_prefill_program_compiles(one_chip):
-    model = model_for(get_config(ARCH))
+def _entry(text: str) -> str:
+    """The ENTRY computation of a compiled module's text."""
+    entry = text[text.index("\nENTRY"):]
+    end = entry.find("\n}\n")
+    return entry if end < 0 else entry[:end]
+
+
+@pytest.mark.parametrize("batch", [1, SLOTS])
+def test_engine_prefill_program_compiles(one_chip, batch):
+    """The served prefill program computes the next token's logits only:
+    no (b, 512, vocab) unembedding (XLA prunes it by itself at b >= 2
+    but not at b = 1), and the last layer reads the stacked weights in
+    place: no ENTRY instruction but a parameter, a loop output or a
+    bitcast holds a whole (n_layers, ...) weight stack."""
+    cfg = get_config(ARCH)
+    model = model_for(cfg)
     params = _on(model.abstract_params(), one_chip)
-    tokens = jax.ShapeDtypeStruct((SLOTS, PREFILL_SEQ), jnp.int32, sharding=one_chip)
-    _fits(prefill_program(model).lower(params, tokens).compile())
+    tokens = jax.ShapeDtypeStruct((batch, PREFILL_SEQ), jnp.int32, sharding=one_chip)
+    compiled = prefill_program(model).lower(params, tokens).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert f"f32[{batch},{PREFILL_SEQ},{cfg.vocab_size}]" not in text
+    stacked = re.compile(rf"= \w+\[{cfg.n_layers},[^\]]*\]\S* (\S+)\(")
+    held = [
+        line.strip() for line in _entry(text).splitlines()[1:]
+        if (m := stacked.search(line))
+        and m.group(1) not in ("parameter", "get-tuple-element", "bitcast")
+    ]
+    assert not held, held
 
 
 def _kernel_text(fn, *args) -> str:
